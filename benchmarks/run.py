@@ -18,6 +18,8 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="reduced workloads (CI-sized)")

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, sharding
+from repro import sharding
 from repro.configs import base
 from repro.models import recsys as model
 from repro.kernels import ops as kops
@@ -139,7 +139,7 @@ def _retrieve(query, cand_emb, *, k, tile):
                 n = jax.lax.psum(n, ax)
             return s, i, n
 
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(axes, None)),
             out_specs=(P(), P(), P()),

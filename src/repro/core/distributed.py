@@ -22,9 +22,8 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.types import (TripleStore, RelaxTable, EngineResult,
                               EngineConfig, PAD_KEY)
 from repro.core import kg as kglib
@@ -49,12 +48,15 @@ class ShardedKG:
 
 
 def shard_workload(pattern_lists, n_shards: int,
-                   list_len: int | None = None) -> ShardedKG:
+                   list_len: int | None = None
+                   ) -> tuple[TripleStore, np.ndarray]:
     """Partition per-pattern (keys, raw_scores) lists into S shard stores.
 
     Scores are normalized by the GLOBAL per-pattern max before sharding
     (Definition 5 is a global property), and the global two-bucket stats are
     computed on the full lists; shard stores keep their local lists sorted.
+    Returns host (numpy) arrays: the shard stores stacked on a leading
+    (S,) axis and the (P, 4) global stats.
     """
     P_n = len(pattern_lists)
     norm_lists = []
@@ -95,19 +97,31 @@ def shard_workload(pattern_lists, n_shards: int,
         for (k, sn), sid in zip(norm_lists, shard_ids):
             sel = sid == s_id
             per_pattern.append((k[sel].astype(np.int32), sn[sel]))
-        st = kglib.build_store(per_pattern, list_len=list_len,
-                               normalize=False, sketch_words=sketch_words)
-        shard_stores.append(st)
+        shard_stores.append(kglib.build_store_host(
+            per_pattern, list_len=list_len, normalize=False,
+            sketch_words=sketch_words))
 
-    stores = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *shard_stores)
-    return stores, jnp.asarray(g_stats)
+    stores = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *shard_stores)
+    return stores, g_stats
 
 
 def build_sharded_kg(pattern_lists, relax: RelaxTable,
-                     n_shards: int, list_len: int | None = None) -> ShardedKG:
+                     n_shards: int, list_len: int | None = None,
+                     mesh: jax.sharding.Mesh | None = None) -> ShardedKG:
+    """Hash-partition the lists into ``n_shards`` stores, placed once.
+
+    With a ``mesh`` each shard goes straight to its own device (sharded
+    over all mesh axes) and the relaxation table and global stats are
+    replicated; without one everything lands on the default device.
+    """
     stores, g_stats = shard_workload(pattern_lists, n_shards, list_len)
-    return ShardedKG(stores=stores, relax=relax, global_stats=g_stats,
+    sh = rep = None
+    if mesh is not None:
+        sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+        rep = NamedSharding(mesh, P())
+    return ShardedKG(stores=jax.device_put(stores, sh),
+                     relax=jax.device_put(relax, rep),
+                     global_stats=jax.device_put(g_stats, rep),
                      n_shards=n_shards)
 
 
@@ -178,6 +192,43 @@ def _shard_body(store: TripleStore, relax: RelaxTable,
                         n_wasted=local.n_wasted[0], relax_mask=mask)
 
 
+def _shard_mapped(body, mesh: jax.sharding.Mesh,
+                  shard_axes: tuple[str, ...]):
+    """shard_map ``body(local_store, relax, gstats, queries)`` over the
+    stacked shard axis; relax, stats and queries are replicated."""
+    rep = P()
+
+    def call(stores, relax, gstats, queries):
+        # Each field of `stores` is (S, P, ...) sharded on axis 0 → the
+        # body sees (1, P, ...); index the unit shard axis away.
+        def local(stores, relax, gstats, queries):
+            return body(jax.tree_util.tree_map(lambda x: x[0], stores),
+                        relax, gstats, queries)
+
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(jax.tree_util.tree_map(lambda _: P(shard_axes), stores),
+                      jax.tree_util.tree_map(lambda _: rep, relax),
+                      rep, rep),
+            out_specs=EngineResult(keys=rep, scores=rep, n_pulled=rep,
+                                   n_answers=rep, n_iters=rep, n_wasted=rep,
+                                   relax_mask=rep),
+            check_vma=False,
+        )(stores, relax, gstats, queries)
+
+    return call
+
+
+@partial(jax.jit, static_argnames=("cfg", "mode", "mesh", "shard_axes"))
+def _run_sharded(stores, relax, gstats, pattern_ids, cfg: EngineConfig,
+                 mode: str, mesh: jax.sharding.Mesh,
+                 shard_axes: tuple[str, ...]) -> EngineResult:
+    return _shard_mapped(
+        lambda st, rl, gs, q: _shard_body(st, rl, gs, q, cfg, mode,
+                                          shard_axes),
+        mesh, shard_axes)(stores, relax, gstats, pattern_ids)
+
+
 def run_query_sharded(skg: ShardedKG, pattern_ids: jax.Array,
                       cfg: EngineConfig, mode: str, mesh: jax.sharding.Mesh,
                       shard_axes: tuple[str, ...] | None = None
@@ -189,60 +240,24 @@ def run_query_sharded(skg: ShardedKG, pattern_ids: jax.Array,
     shard_axes = shard_axes or tuple(mesh.axis_names)
     n_dev = int(np.prod([mesh.shape[a] for a in shard_axes]))
     assert skg.n_shards == n_dev, (skg.n_shards, n_dev)
-
-    store_specs = jax.tree_util.tree_map(
-        lambda _: P(shard_axes), skg.stores)
-    rep = P()
-
-    # Each field of `stores` is (S, P, ...) sharded on axis 0 → the body
-    # sees (1, P, ...); index the unit shard axis away.
-    def body_wrap(stores, relax, gstats, pids):
-        local = jax.tree_util.tree_map(lambda x: x[0], stores)
-        return _shard_body(local, relax, gstats, pids, cfg, mode, shard_axes)
-
-    fn = compat.shard_map(
-        body_wrap, mesh=mesh,
-        in_specs=(store_specs,
-                  jax.tree_util.tree_map(lambda _: rep, skg.relax),
-                  rep, rep),
-        out_specs=EngineResult(keys=rep, scores=rep, n_pulled=rep,
-                               n_answers=rep, n_iters=rep, n_wasted=rep,
-                               relax_mask=rep),
-        check_vma=False,
-    )
-    return fn(skg.stores, skg.relax, skg.global_stats, pattern_ids)
+    return _run_sharded(skg.stores, skg.relax, skg.global_stats,
+                        pattern_ids, cfg=cfg, mode=mode, mesh=mesh,
+                        shard_axes=shard_axes)
 
 
 def make_batched_sharded_fn(cfg: EngineConfig, mode: str,
                             mesh: jax.sharding.Mesh,
                             shard_axes: tuple[str, ...] | None = None):
-    """Build fn(stores, relax, gstats, queries (B,T)) → EngineResult batch.
+    """Build jit(fn(stores, relax, gstats, queries (B,T))) → EngineResult.
 
     This is the production serve_step the dry-run lowers: every device runs
     the planner + executor on its KG partition for the whole query batch
     (vmap), then the per-axis gather/top-k tree merges results.
     """
     shard_axes = shard_axes or tuple(mesh.axis_names)
-    rep = P()
 
-    def body(stores, relax, gstats, queries):
-        local = jax.tree_util.tree_map(lambda x: x[0], stores)
-        run = lambda q: _shard_body(local, relax, gstats, q, cfg, mode,
-                                    shard_axes)
-        return jax.vmap(run)(queries)
+    def body(local, relax, gstats, queries):
+        return jax.vmap(lambda q: _shard_body(local, relax, gstats, q, cfg,
+                                              mode, shard_axes))(queries)
 
-    def wrapped(stores, relax, gstats, queries):
-        store_specs = jax.tree_util.tree_map(lambda _: P(shard_axes), stores)
-        fn = compat.shard_map(
-            body, mesh=mesh,
-            in_specs=(store_specs,
-                      jax.tree_util.tree_map(lambda _: rep, relax),
-                      rep, rep),
-            out_specs=EngineResult(keys=rep, scores=rep, n_pulled=rep,
-                                   n_answers=rep, n_iters=rep, n_wasted=rep,
-                                   relax_mask=rep),
-            check_vma=False,
-        )
-        return fn(stores, relax, gstats, queries)
-
-    return wrapped
+    return jax.jit(_shard_mapped(body, mesh, shard_axes))

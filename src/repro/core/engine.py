@@ -110,16 +110,15 @@ def _step(streams: ops.MergedStreams, st: _LoopState, cfg: EngineConfig,
     # Drop keys this stream already emitted (earlier pull ⇒ ≥ score).
     _, seen_before = ops.lookup_scores(
         st.seen_keys[t_star], st.seen_scores[t_star], blk_k,
-        st.seen_cnt[t_star], cfg.use_pallas, cfg.pallas_interpret)
+        st.seen_cnt[t_star], cfg.use_pallas)
     blk_k = jnp.where(seen_before, PAD_KEY, blk_k)
     blk_s = jnp.where(seen_before, NEG_INF, blk_s)
 
     # Join the fresh block against every other stream's seen buffer.
     def probe(j):
-        s, f = ops.lookup_scores(
+        return ops.lookup_scores(
             st.seen_keys[j], st.seen_scores[j], blk_k, st.seen_cnt[j],
-            cfg.use_pallas, cfg.pallas_interpret)
-        return s, f
+            cfg.use_pallas)
     s_j, f_j = jax.vmap(probe)(jnp.arange(T))               # (T, B)
     others = active & (jnp.arange(T) != t_star)
     contrib = jnp.sum(jnp.where(others[:, None], s_j, 0.0), axis=0)

@@ -2,10 +2,13 @@
 
 The ingest path is host-side numpy (this is the "database load" phase); the
 result is a pytree of device arrays that every engine entry point consumes.
+``build_store_host`` stops before the device, for callers that place the
+arrays themselves (the sharded build puts each shard on its own device).
 """
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.types import TripleStore, RelaxTable, PAD_KEY, KEY_SENTINEL
@@ -42,7 +45,18 @@ def build_store(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
                 normalize: bool = True,
                 sketch_lanes: int = sketchlib.SKETCH_LANES,
                 sketch_words: int | None = None) -> TripleStore:
-    """Build a TripleStore from per-pattern (keys, raw_scores) host arrays.
+    """``build_store_host`` placed on the default device."""
+    return jax.tree_util.tree_map(
+        jnp.asarray, build_store_host(pattern_lists, list_len, normalize,
+                                      sketch_lanes, sketch_words))
+
+
+def build_store_host(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
+                     list_len: int | None = None,
+                     normalize: bool = True,
+                     sketch_lanes: int = sketchlib.SKETCH_LANES,
+                     sketch_words: int | None = None) -> TripleStore:
+    """Build a host (numpy) TripleStore from per-pattern (keys, raw_scores).
 
     Scores are normalized per Definition 5 (divide by the list max) unless
     ``normalize=False`` (used by the sharded build, where normalization by
@@ -98,14 +112,9 @@ def build_store(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
             max((len(k) for k, _ in pattern_lists), default=1))
     sketch = sketchlib.build_sketches([k for k, _ in pattern_lists],
                                       lanes=sketch_lanes, words=sketch_words)
-    return TripleStore(
-        keys=jnp.asarray(keys),
-        scores=jnp.asarray(scores),
-        lengths=jnp.asarray(lengths),
-        sorted_keys=jnp.asarray(sorted_keys),
-        stats=jnp.asarray(stats),
-        sketch=jnp.asarray(sketch),
-    )
+    return TripleStore(keys=keys, scores=scores, lengths=lengths,
+                       sorted_keys=sorted_keys, stats=stats,
+                       sketch=sketch)
 
 
 def build_relax_table(P: int,

@@ -23,23 +23,34 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import PAD_KEY, NEG_INF
+from repro.kernels import ref as kref
 
 
 def lookup_scores(seen_keys: jax.Array, seen_scores: jax.Array,
                   probe_keys: jax.Array, seen_cnt: jax.Array,
-                  use_pallas: bool = False, interpret: bool = True):
+                  use_pallas: bool = False):
     """Probe ``probe_keys`` (B,) against a unique-key buffer (N,).
 
     Returns (scores (B,) f32 with 0 where missing, found (B,) bool).
+    ``use_pallas`` routes the probe to the rank-join kernel, which the
+    platform decides how to run (``kernels.ops``); otherwise the kernel's
+    jnp oracle probes the buffer, in tiles when it is long.
+
+    Live window: slots written at least once. seen_cnt counts appended
+    items cumulatively; once the ring wraps (seen_cnt >= N) every slot
+    holds current data — ring alignment (N a multiple of the block) in
+    the engine guarantees wrapped appends replace whole stale blocks, so
+    "written" == "live" and no half-overwritten fragment survives.
     """
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.rank_join_lookup(seen_keys, seen_scores, probe_keys,
-                                     seen_cnt, interpret=interpret)
+                                     seen_cnt, impl="pallas")
     n = seen_keys.shape[0]
     tile = 4096
     if n <= tile:
-        return _lookup_dense(seen_keys, seen_scores, probe_keys, seen_cnt, 0)
+        return kref.rank_join_lookup_ref(seen_keys, seen_scores, probe_keys,
+                                         seen_cnt)
     # Tiled scan mirrors the Pallas kernel's streaming: transient memory is
     # B×tile instead of B×N (matters for the production-scale KG cells).
     pad = -n % tile
@@ -52,7 +63,7 @@ def lookup_scores(seen_keys: jax.Array, seen_scores: jax.Array,
     def body(carry, xs):
         acc_s, acc_f, base = carry
         k, s = xs
-        ds, df = _lookup_dense(k, s, probe_keys, seen_cnt, base)
+        ds, df = kref.rank_join_lookup_ref(k, s, probe_keys, seen_cnt, base)
         return (acc_s + ds, acc_f | df, base + tile), None
 
     (scores, found, _), _ = jax.lax.scan(
@@ -60,23 +71,6 @@ def lookup_scores(seen_keys: jax.Array, seen_scores: jax.Array,
         (jnp.zeros_like(probe_keys, jnp.float32),
          jnp.zeros(probe_keys.shape, bool), jnp.int32(0)),
         (kt, st))
-    return jnp.where(found, scores, 0.0), found
-
-
-def _lookup_dense(seen_keys, seen_scores, probe_keys, seen_cnt, base):
-    n = seen_keys.shape[0]
-    # Live window: slots written at least once. seen_cnt counts appended
-    # items cumulatively; once the ring wraps (seen_cnt >= N) every slot
-    # holds current data — ring alignment (N a multiple of the block) in
-    # the engine guarantees wrapped appends replace whole stale blocks, so
-    # "written" == "live" and no half-overwritten fragment survives.
-    live = (base + jnp.arange(n)) < seen_cnt
-    valid_seen = (seen_keys != PAD_KEY) & live
-    eq = (probe_keys[:, None] == seen_keys[None, :]) & valid_seen[None, :]
-    eqf = eq.astype(jnp.float32)
-    scores = eqf @ jnp.where(valid_seen, seen_scores, 0.0)
-    found = (eqf @ valid_seen.astype(jnp.float32)) > 0.5
-    found = found & (probe_keys != PAD_KEY)
     return jnp.where(found, scores, 0.0), found
 
 

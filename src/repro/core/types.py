@@ -108,9 +108,9 @@ class EngineConfig:
     # uses the bitmap signatures (O(W) per probe, L-independent — see
     # sketches.py / DESIGN.md §6).
     cardinality_mode: str = "exact"
-    use_pallas: bool = False  # dispatch joins/merges to Pallas kernels
-    # Interpret mode for Pallas on CPU; ignored on TPU.
-    pallas_interpret: bool = True
+    # Probe seen rings with the Pallas rank-join kernel: compiled on a TPU,
+    # run by the Pallas interpreter anywhere else (kernels/ops.py).
+    use_pallas: bool = False
     # Cap on the per-stream seen buffer (None = worst-case R1·L sizing).
     # The executor rounds the cap up to a whole number of blocks so the
     # ring wraps block-aligned (see engine._seen_size).

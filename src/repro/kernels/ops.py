@@ -3,7 +3,9 @@
 Every op picks the Pallas kernel on TPU (interpret=False) and either the
 interpret-mode kernel or the pure-jnp oracle elsewhere. Callers can force a
 path with ``impl`` ∈ {"auto", "pallas", "ref"} — benchmarks and tests use
-that to compare paths on identical inputs.
+that to compare paths on identical inputs. Whether a kernel is compiled or
+interpreted is never the caller's choice: it is compiled exactly when the
+default backend is a TPU.
 """
 from __future__ import annotations
 
@@ -35,11 +37,8 @@ def _resolve(impl: str) -> tuple[bool, bool]:
 
 
 def rank_join_lookup(seen_keys, seen_scores, probe_keys, seen_cnt,
-                     impl: str = "auto", interpret: bool | None = None):
+                     impl: str = "auto"):
     use_pallas, interp = _resolve(impl)
-    if interpret is not None:
-        interp = interpret
-        use_pallas = True
     if use_pallas:
         return _rank_join.rank_join_lookup(
             seen_keys, seen_scores, probe_keys, seen_cnt, interpret=interp)

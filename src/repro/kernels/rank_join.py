@@ -6,7 +6,10 @@ vector is exactly a QKᵀ-shaped MXU tile — this is the TPU-native form of
 the paper's rank-join inner loop (DESIGN.md §2).
 
 Grid: sequential over N/TILE_N seen tiles, accumulating into the (B, 1)
-outputs (constant output block mapping ⇒ revisiting accumulation).
+outputs (constant output block mapping ⇒ revisiting accumulation). The live
+count ``seen_cnt`` is a (1, 1) scalar in SMEM: under the engine's lane and
+stream ``vmap`` it becomes one SMEM scalar per grid step of the batch axes,
+where a VMEM block of it would break the (8, 128) tiling rule.
 """
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 PAD_KEY = -1
 
@@ -32,13 +36,15 @@ def _lookup_kernel(cnt_ref, probe_ref, keys_ref, scores_ref,
     keys = keys_ref[...]                     # (1, TILE_N) int32
     scores = scores_ref[...]                 # (1, TILE_N) f32
     pos = j * tile_n + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
-    valid = (keys != PAD_KEY) & (pos < cnt_ref[0])
+    valid = (keys != PAD_KEY) & (pos < cnt_ref[0, 0])
     eq = (probes == keys) & valid            # (B, TILE_N)
     eqf = eq.astype(jnp.float32)
     # MXU contraction: matched score (sum == the unique match) and count.
+    # The score contraction keeps full f32 precision (the match is exact).
     out_s_ref[...] += jax.lax.dot_general(
         eqf, jnp.where(valid, scores, 0.0),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     out_f_ref[...] += jax.lax.dot_general(
         eqf, valid.astype(jnp.float32),
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -61,7 +67,8 @@ def rank_join_lookup(seen_keys: jax.Array, seen_scores: jax.Array,
         functools.partial(_lookup_kernel, tile_n=tile_n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda j: (0,)),
+            pl.BlockSpec((1, 1), lambda j: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((b, 1), lambda j: (0, 0)),
             pl.BlockSpec((1, tile_n), lambda j: (0, j)),
             pl.BlockSpec((1, tile_n), lambda j: (0, j)),
@@ -75,7 +82,7 @@ def rank_join_lookup(seen_keys: jax.Array, seen_scores: jax.Array,
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(seen_cnt.reshape(1), probe_keys[:, None],
+    )(seen_cnt.reshape(1, 1), probe_keys[:, None],
       seen_keys[None, :], seen_scores[None, :])
 
     found = (out_f[:, 0] > 0.5) & (probe_keys != PAD_KEY)

@@ -12,18 +12,25 @@ PAD_KEY = jnp.int32(-1)
 NEG_INF = jnp.float32(-jnp.inf)
 
 
-def rank_join_lookup_ref(seen_keys, seen_scores, probe_keys, seen_cnt):
+def rank_join_lookup_ref(seen_keys, seen_scores, probe_keys, seen_cnt,
+                         base=0):
     """Probe keys against a unique-key scored buffer.
 
     seen_keys/seen_scores: (N,); probe_keys: (B,); seen_cnt: () int32.
+    ``base`` is the buffer position of slot 0, for a caller that probes a
+    longer buffer tile by tile: slot i is live iff base + i < seen_cnt.
     Returns (scores (B,) f32 — 0 where missing, found (B,) bool).
     """
     n = seen_keys.shape[0]
-    live = jnp.arange(n) < seen_cnt
+    live = (base + jnp.arange(n)) < seen_cnt
     valid = (seen_keys != PAD_KEY) & live
     eq = (probe_keys[:, None] == seen_keys[None, :]) & valid[None, :]
     eqf = eq.astype(jnp.float32)
-    scores = eqf @ jnp.where(valid, seen_scores, 0.0)
+    # HIGHEST, as in the kernel: the TPU's default f32 matmul rounds
+    # operands to bf16, which would return the matched score to 3
+    # significant digits.
+    scores = jnp.matmul(eqf, jnp.where(valid, seen_scores, 0.0),
+                        precision=jax.lax.Precision.HIGHEST)
     found = (eqf @ valid.astype(jnp.float32)) > 0.5
     found = found & (probe_keys != PAD_KEY)
     return jnp.where(found, scores, 0.0), found

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.core import engine
 from repro.core.types import EngineConfig
 from repro.data import kg_synth
-from repro.launch import batching
+from repro.launch import batching, compile_cache
 
 
 def sequential_baseline(wl, cfg, mode, queries):
@@ -44,6 +44,7 @@ def sequential_baseline(wl, cfg, mode, queries):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="xkg_mini",
                     choices=["xkg_mini", "twitter_mini"])

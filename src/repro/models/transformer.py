@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat, sharding
+from repro import sharding
 from repro.models import common as cm
 from repro.models import attention as attn
 from repro.models import moe as ffnlib
@@ -163,7 +163,7 @@ def _stack_fwd(stack_params, cfg: LMConfig, dense: bool, x, positions,
             # Barrier: keeps the scan's saved-residual stack in the carry's
             # own dtype (bf16) — without it XLA hoists the backward's f32
             # convert into the stacking write, doubling activation memory.
-            x_ = compat.opt_barrier(x_)
+            x_ = jax.lax.optimization_barrier(x_)
             return _layer_fwd(lp, cfg, dense, x_, positions, win)
         if cfg.remat == "full":
             inner = jax.checkpoint(

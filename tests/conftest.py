@@ -1,13 +1,11 @@
 import os
-import random
-import sys
-import types
 from functools import lru_cache
 
 import pytest
 
-# Smoke tests and benches see the single real device; only the dry-run
-# forces 512 placeholder devices (and does so in its own process).
+# Tests run on the CPU (JAX_PLATFORMS=cpu): one real device, Pallas kernels
+# in interpret mode. Only the dry-run forces 512 placeholder devices, in its
+# own process; the chip is exercised by chip_smoke.py, not by pytest.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # ---------------------------------------------------------------------------
@@ -45,9 +43,9 @@ def wl_factory():
 # Trace-count probe (promoted from tests/test_speclint.py so every module
 # can guard against retrace regressions): measures how many NEW jit
 # specializations a block of calls compiles. jax's jitted callables expose
-# the compiled-specialization count as ``fn._cache_size()`` (jax 0.4.x);
-# the fixture hides that private probe behind one seam so a jax upgrade
-# only patches this spot.
+# the compiled-specialization count as the private ``fn._cache_size()``;
+# the fixture hides that probe behind one seam so a jax upgrade only
+# patches this spot.
 # ---------------------------------------------------------------------------
 
 @pytest.fixture
@@ -62,95 +60,3 @@ def jit_trace_growth():
             jax.block_until_ready(call())
         return jitted_fn._cache_size() - before
     return growth
-
-# ---------------------------------------------------------------------------
-# Optional-dependency shim: `hypothesis` is not part of the baked image.
-# When it is missing we install a tiny deterministic stand-in so the
-# property-test modules still collect and run — each @given test executes
-# against a fixed pseudo-random sample of its strategy space (seeded, so
-# runs are reproducible) instead of hypothesis' adaptive search.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw  # draw(rng) -> value
-
-    def _floats(min_value=0.0, max_value=1.0, **_):
-        return _Strategy(
-            lambda rng: min_value + (max_value - min_value) * rng.random())
-
-    def _integers(min_value=0, max_value=1 << 30):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[rng.randrange(len(seq))])
-
-    def _lists(elements, min_size=0, max_size=10, **_):
-        def draw(rng):
-            n = rng.randint(min_size, max_size)
-            return [elements.draw(rng) for _ in range(n)]
-        return _Strategy(draw)
-
-    _DEFAULT_EXAMPLES = 10
-
-    class _UnsatisfiedAssumption(Exception):
-        """Raised by the stub `assume` to discard the current example."""
-
-    def _given(*gargs, **gkwargs):
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                rng = random.Random(0)
-                n = getattr(fn, "_stub_max_examples",
-                            getattr(wrapper, "_stub_max_examples",
-                                    _DEFAULT_EXAMPLES))
-                ran = 0
-                for _ in range(n * 10):
-                    if ran >= n:
-                        break
-                    vals = [s.draw(rng) for s in gargs]
-                    kvals = {k: s.draw(rng) for k, s in gkwargs.items()}
-                    try:
-                        fn(*args, *vals, **kwargs, **kvals)
-                        ran += 1
-                    except _UnsatisfiedAssumption:
-                        continue
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            return wrapper
-        return deco
-
-    def _settings(max_examples=_DEFAULT_EXAMPLES, **_):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    def _assume(condition):
-        if not condition:
-            raise _UnsatisfiedAssumption
-        return True
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.floats = _floats
-    _st.integers = _integers
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = _assume
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(too_slow=None, filter_too_much=None)
-    _hyp.__is_repro_stub__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

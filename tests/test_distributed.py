@@ -13,7 +13,7 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.data import kg_synth
 from repro.core import engine, distributed
 from repro.core.types import EngineConfig
@@ -25,8 +25,9 @@ for p in range(P):
     n = int(wl.store.lengths[p])
     lists.append((np.asarray(wl.store.keys[p][:n]),
                   np.asarray(wl.store.scores[p][:n])))
-mesh = compat.make_mesh((4, 2), ("data", "model"))
-skg = distributed.build_sharded_kg(lists, wl.relax, 8)
+mesh = make_mesh((4, 2), ("data", "model"))
+skg = distributed.build_sharded_kg(lists, wl.relax, 8, mesh=mesh)
+assert len({s.device for s in skg.stores.keys.addressable_shards}) == 8
 cfg = EngineConfig(block=8, k=5, grid_bins=128)
 for i in range(len(wl.queries)):
     q = jnp.asarray(wl.queries[i])
@@ -88,7 +89,7 @@ def test_shard_workload_survives_hash_skew():
 def test_distributed_engine_equivalence():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1800,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

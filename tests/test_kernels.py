@@ -1,6 +1,7 @@
 """Pallas kernel sweeps: shapes/dtypes vs the ref.py oracles (interpret)."""
 import numpy as np
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -28,6 +29,36 @@ def test_rank_join_lookup(N, B, frac):
                                  jnp.asarray(probes), jnp.int32(cnt))
     np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[0]), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+
+
+def _dot_precisions(jaxpr) -> list:
+    """The ``precision`` of every dot_general in ``jaxpr``, in program
+    order, descending into sub-jaxprs (jit bodies, Pallas kernel bodies)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if isinstance(sub, jax.extend.core.Jaxpr):
+                out.extend(_dot_precisions(sub))
+    return out
+
+
+def test_rank_join_oracle_contracts_at_kernel_precision():
+    """The oracle must round the score contraction as the kernel does: at
+    default precision the TPU matmul passes f32 operands as bf16, so an
+    oracle and kernel that disagree on precision disagree in the 3rd
+    digit on the chip (and agree on the CPU, where nothing would show)."""
+    args = (jnp.zeros((512,), jnp.int32), jnp.zeros((512,), jnp.float32),
+            jnp.zeros((16,), jnp.int32), jnp.int32(0))
+    kern = _dot_precisions(jax.make_jaxpr(
+        lambda *a: rank_join.rank_join_lookup(*a, interpret=True))(*args)
+        .jaxpr)
+    orac = _dot_precisions(jax.make_jaxpr(ref.rank_join_lookup_ref)(*args)
+                           .jaxpr)
+    assert len(kern) == 2 and kern == orac, (kern, orac)
+    assert kern[0] is not None                 # the score contraction
 
 
 def test_rank_join_matches_step_probe_semantics():

@@ -97,7 +97,7 @@ def _refill_executor(wl, mode="specqp", lanes=2, refill_depth=8,
     return batching.BatchExecutor(wl.store, wl.relax, CFG, mode, bcfg)
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=5),
        n=st.integers(min_value=1, max_value=10),
        lanes=st.sampled_from((1, 2, 4)),

@@ -123,7 +123,7 @@ def test_offline_executor_equivalence(mode):
     assert 0.0 <= ex.wasted_fraction() < 1.0
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=3),
        n=st.integers(min_value=1, max_value=7),
        mode=st.sampled_from(("specqp", "join_only")))

@@ -1,14 +1,15 @@
 """Logical-axis sharding rules: divisibility, dedupe, no-mesh no-ops."""
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat, sharding
+from repro import sharding
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture
 def mesh():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_noop_without_mesh():
@@ -35,7 +36,7 @@ def test_spec_dedupes_axes(mesh):
 
 
 def test_divisibility_16way():
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     rules = dict(sharding.DEFAULT_RULES)
     with sharding.use_rules(mesh, rules):
         # 7 % 1 == 0 → axis kept (size-1 mesh)
@@ -44,7 +45,7 @@ def test_divisibility_16way():
 
 def test_tuple_rule_prefix():
     # AbstractMesh suffices for spec logic (no devices needed).
-    mesh = compat.abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     rules = dict(sharding.DEFAULT_RULES)
     rules["x2"] = ("data", "model")
     with sharding.use_rules(mesh, rules):

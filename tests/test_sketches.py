@@ -78,7 +78,7 @@ def test_fixed_width_override_and_shard_geometry():
         sketches.SKETCH_LANES, sketches.adaptive_words(100))
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000),
        shared=st.integers(min_value=0, max_value=80),
        n_sets=st.integers(min_value=2, max_value=4))
@@ -96,7 +96,7 @@ def test_intersection_estimate_close_to_exact(seed, shared, n_sets):
     assert abs(est - exact) <= tol, (exact, est)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
 def test_joinability_zero_is_truly_zero(seed):
     """Whenever the raw sketch estimator reports a 0 joinable count, the
