@@ -28,6 +28,7 @@ from repro.core.types import (TripleStore, RelaxTable, EngineResult,
                               EngineConfig, PAD_KEY, NEG_INF)
 from repro.core import operators as ops
 from repro.core import plangen
+from repro.core import scopes
 
 
 class _LoopState(NamedTuple):
@@ -86,48 +87,47 @@ def _step(streams: ops.MergedStreams, st: _LoopState, cfg: EngineConfig,
     T, R1, L = streams.keys.shape
     B = cfg.block
     k = cfg.k
-
-    stream_max = jnp.max(
-        jnp.where(streams.lengths > 0, streams.scores[:, :, 0], NEG_INF),
-        axis=1)                                                 # (T,)
-    stream_max = jnp.where(streams.stream_active, stream_max, NEG_INF)
     active = streams.stream_active
-    sum_max = jnp.sum(jnp.where(active, stream_max, 0.0))
 
     def head_scores(cursors):
         return jax.vmap(ops.merged_head_score)(
             streams.keys, streams.scores, streams.lengths, cursors)
 
-    nxt = head_scores(st.cursors)                           # (T,)
-    nxt = jnp.where(active, nxt, NEG_INF)
-    t_star = jnp.argmax(nxt)
+    with jax.named_scope(scopes.PULL):
+        nxt = head_scores(st.cursors)                       # (T,)
+        nxt = jnp.where(active, nxt, NEG_INF)
+        t_star = jnp.argmax(nxt)
 
-    blk_k, blk_s, new_cur_t = ops.pull_block(
-        streams.keys[t_star], streams.scores[t_star],
-        streams.lengths[t_star], st.cursors[t_star], B)
-    n_taken = jnp.sum(blk_k != PAD_KEY)
-    blk_k, blk_s = ops.dedup_block(blk_k, blk_s)
-    # Drop keys this stream already emitted (earlier pull ⇒ ≥ score).
-    _, seen_before = ops.lookup_scores(
-        st.seen_keys[t_star], st.seen_scores[t_star], blk_k,
-        st.seen_cnt[t_star], cfg.use_pallas)
-    blk_k = jnp.where(seen_before, PAD_KEY, blk_k)
-    blk_s = jnp.where(seen_before, NEG_INF, blk_s)
+        blk_k, blk_s, new_cur_t = ops.pull_block(
+            streams.keys[t_star], streams.scores[t_star],
+            streams.lengths[t_star], st.cursors[t_star], B)
+        n_taken = jnp.sum(blk_k != PAD_KEY)
+        blk_k, blk_s = ops.dedup_block(blk_k, blk_s)
 
-    # Join the fresh block against every other stream's seen buffer.
-    def probe(j):
-        return ops.lookup_scores(
-            st.seen_keys[j], st.seen_scores[j], blk_k, st.seen_cnt[j],
-            cfg.use_pallas)
-    s_j, f_j = jax.vmap(probe)(jnp.arange(T))               # (T, B)
-    others = active & (jnp.arange(T) != t_star)
-    contrib = jnp.sum(jnp.where(others[:, None], s_j, 0.0), axis=0)
-    matched = jnp.all(jnp.where(others[:, None], f_j, True), axis=0)
-    cand_ok = matched & (blk_k != PAD_KEY)
-    cand_scores = jnp.where(cand_ok, blk_s + contrib, NEG_INF)
-    cand_keys = jnp.where(cand_ok, blk_k, PAD_KEY)
-    top_keys, top_scores = ops.topk_insert(
-        st.top_keys, st.top_scores, cand_keys, cand_scores, k)
+    with jax.named_scope(scopes.PROBE):
+        # Drop keys this stream already emitted (earlier pull ⇒ ≥ score).
+        _, seen_before = ops.lookup_scores(
+            st.seen_keys[t_star], st.seen_scores[t_star], blk_k,
+            st.seen_cnt[t_star], cfg.use_pallas)
+        blk_k = jnp.where(seen_before, PAD_KEY, blk_k)
+        blk_s = jnp.where(seen_before, NEG_INF, blk_s)
+
+        # Join the fresh block against every other stream's seen buffer.
+        def probe(j):
+            return ops.lookup_scores(
+                st.seen_keys[j], st.seen_scores[j], blk_k, st.seen_cnt[j],
+                cfg.use_pallas)
+        s_j, f_j = jax.vmap(probe)(jnp.arange(T))           # (T, B)
+        others = active & (jnp.arange(T) != t_star)
+        contrib = jnp.sum(jnp.where(others[:, None], s_j, 0.0), axis=0)
+        matched = jnp.all(jnp.where(others[:, None], f_j, True), axis=0)
+        cand_ok = matched & (blk_k != PAD_KEY)
+        cand_scores = jnp.where(cand_ok, blk_s + contrib, NEG_INF)
+        cand_keys = jnp.where(cand_ok, blk_k, PAD_KEY)
+
+    with jax.named_scope(scopes.TOPK):
+        top_keys, top_scores = ops.topk_insert(
+            st.top_keys, st.top_scores, cand_keys, cand_scores, k)
 
     # Append the block to t*'s seen buffer (fixed B slots per pull;
     # wraps as a ring when a seen_cap is configured). N is a multiple
@@ -137,38 +137,45 @@ def _step(streams: ops.MergedStreams, st: _LoopState, cfg: EngineConfig,
     # executor's lane vmap, and a slice update with per-lane starts
     # lowers to an XLA scatter that the CPU backend runs as a scalar
     # loop under vmap.
-    blk_s_store = jnp.where(blk_s == NEG_INF, 0.0, blk_s)
+    with jax.named_scope(scopes.APPEND):
+        blk_s_store = jnp.where(blk_s == NEG_INF, 0.0, blk_s)
 
-    def append(t):
-        start = st.seen_cnt[t] % jnp.int32(N)
-        rel = jnp.arange(N) - start                    # (N,)
-        oh = rel[:, None] == jnp.arange(B)[None, :]    # (N, B)
-        in_win = (rel >= 0) & (rel < B)
-        upd_k = jnp.where(
-            in_win,
-            jnp.sum(jnp.where(oh, blk_k[None, :], 0), axis=1),
-            st.seen_keys[t])
-        upd_s = jnp.where(
-            in_win,
-            jnp.sum(jnp.where(oh, blk_s_store[None, :], 0.0), axis=1),
-            st.seen_scores[t])
-        sel = t == t_star
-        return (jnp.where(sel, upd_k, st.seen_keys[t]),
-                jnp.where(sel, upd_s, st.seen_scores[t]))
-    seen_keys, seen_scores = jax.vmap(append)(jnp.arange(T))
-    seen_cnt = st.seen_cnt + jnp.where(
-        jnp.arange(T) == t_star, B, 0).astype(jnp.int32)
-    cursors = jax.vmap(
-        lambda t, nc: jnp.where(t == t_star, nc, st.cursors[t]),
-        in_axes=(0, None))(jnp.arange(T), new_cur_t)
+        def append(t):
+            start = st.seen_cnt[t] % jnp.int32(N)
+            rel = jnp.arange(N) - start                    # (N,)
+            oh = rel[:, None] == jnp.arange(B)[None, :]    # (N, B)
+            in_win = (rel >= 0) & (rel < B)
+            upd_k = jnp.where(
+                in_win,
+                jnp.sum(jnp.where(oh, blk_k[None, :], 0), axis=1),
+                st.seen_keys[t])
+            upd_s = jnp.where(
+                in_win,
+                jnp.sum(jnp.where(oh, blk_s_store[None, :], 0.0), axis=1),
+                st.seen_scores[t])
+            sel = t == t_star
+            return (jnp.where(sel, upd_k, st.seen_keys[t]),
+                    jnp.where(sel, upd_s, st.seen_scores[t]))
+        seen_keys, seen_scores = jax.vmap(append)(jnp.arange(T))
+        seen_cnt = st.seen_cnt + jnp.where(
+            jnp.arange(T) == t_star, B, 0).astype(jnp.int32)
+        cursors = jax.vmap(
+            lambda t, nc: jnp.where(t == t_star, nc, st.cursors[t]),
+            in_axes=(0, None))(jnp.arange(T), new_cur_t)
 
     # HRJN-style n-ary corner bound for any undiscovered answer.
-    nxt2 = head_scores(cursors)
-    nxt2 = jnp.where(active, nxt2, NEG_INF)
-    tau = jnp.max(nxt2 + (sum_max - jnp.where(active, stream_max, 0.0)))
-    kth = top_scores[k - 1]
-    exhausted = jnp.all(nxt2 == NEG_INF)
-    done = (kth >= tau) | exhausted
+    with jax.named_scope(scopes.BOUND):
+        stream_max = jnp.max(
+            jnp.where(streams.lengths > 0, streams.scores[:, :, 0], NEG_INF),
+            axis=1)                                             # (T,)
+        stream_max = jnp.where(active, stream_max, NEG_INF)
+        sum_max = jnp.sum(jnp.where(active, stream_max, 0.0))
+        nxt2 = head_scores(cursors)
+        nxt2 = jnp.where(active, nxt2, NEG_INF)
+        tau = jnp.max(nxt2 + (sum_max - jnp.where(active, stream_max, 0.0)))
+        kth = top_scores[k - 1]
+        exhausted = jnp.all(nxt2 == NEG_INF)
+        done = (kth >= tau) | exhausted
 
     return _LoopState(
         cursors=cursors, seen_keys=seen_keys, seen_scores=seen_scores,
@@ -279,8 +286,9 @@ def _execute_refill(store: TripleStore, relax: RelaxTable,
     trips_cap = M * max_iters + 2
 
     def admit(i):
-        return ops.gather_streams(store, relax, queue_pids[i],
-                                  queue_masks[i])
+        with jax.named_scope(scopes.ADMIT):
+            return ops.gather_streams(store, relax, queue_pids[i],
+                                      queue_masks[i])
 
     lane0 = jnp.minimum(jnp.arange(Q), M - 1)
     live0 = jnp.arange(Q) < M
@@ -340,10 +348,11 @@ def _execute_refill(store: TripleStore, relax: RelaxTable,
 
         def do_refill(args):
             st, streams, qidx = args
-            fresh = jax.vmap(admit)(jnp.clip(cand, 0, M - 1))
-            st2, streams2 = _splice_lanes(st, streams, fresh, refill)
-            return st2, streams2, jnp.where(refill, cand, qidx).astype(
-                jnp.int32)
+            with jax.named_scope(scopes.ADMIT):
+                fresh = jax.vmap(admit)(jnp.clip(cand, 0, M - 1))
+                st2, streams2 = _splice_lanes(st, streams, fresh, refill)
+                return st2, streams2, jnp.where(refill, cand, qidx).astype(
+                    jnp.int32)
 
         # The cond skips the per-lane re-gather on the (common) trips
         # where no lane finished.

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.types import TripleStore, RelaxTable, PAD_KEY, KEY_SENTINEL
 from repro.core import histogram
+from repro.core import scopes
 from repro.core import sketches
 
 
@@ -147,11 +148,12 @@ def cardinalities(store: TripleStore, relax: RelaxTable,
     Both flavors are local to the store they are given and ``psum`` to
     global values under hash partitioning.
     """
-    if mode == "exact":
-        return exact_cardinalities(store, relax, pattern_ids, active)
-    if mode == "sketch":
-        return sketches.sketch_cardinalities(store, relax, pattern_ids,
-                                             active)
+    with jax.named_scope(scopes.CARDINALITY):
+        if mode == "exact":
+            return exact_cardinalities(store, relax, pattern_ids, active)
+        if mode == "sketch":
+            return sketches.sketch_cardinalities(store, relax, pattern_ids,
+                                                 active)
     raise ValueError(f"unknown cardinality_mode: {mode!r}")
 
 
@@ -165,11 +167,12 @@ def joinability(store: TripleStore, relax: RelaxTable,
     rounds sub-half-key global estimates to 0 (``sketches.
     round_joinability``), a bounded approximation of the exact prune.
     """
-    if mode == "exact":
-        return joinable_counts(store, relax, pattern_ids, active)
-    if mode == "sketch":
-        return sketches.sketch_joinable_counts(store, relax, pattern_ids,
-                                               active)
+    with jax.named_scope(scopes.JOINABILITY):
+        if mode == "exact":
+            return joinable_counts(store, relax, pattern_ids, active)
+        if mode == "sketch":
+            return sketches.sketch_joinable_counts(store, relax, pattern_ids,
+                                                   active)
     raise ValueError(f"unknown cardinality_mode: {mode!r}")
 
 
@@ -213,34 +216,35 @@ def score_estimates_from_cards(stats_table: jax.Array, relax: RelaxTable,
     ``stats_table`` is the *global* (P, 4) statistics array — tiny and
     replicated in the distributed engine.
     """
-    T = pattern_ids.shape[0]
-    R = relax.ids.shape[1]
-    safe_ids = jnp.where(pattern_ids == PAD_KEY, 0, pattern_ids)
-    stats = stats_table[safe_ids]                      # (T, 4)
-    pmfs = jax.vmap(lambda s: histogram.pattern_pmf(s, 1.0, G))(stats)
+    with jax.named_scope(scopes.ESTIMATE):
+        T = pattern_ids.shape[0]
+        R = relax.ids.shape[1]
+        safe_ids = jnp.where(pattern_ids == PAD_KEY, 0, pattern_ids)
+        stats = stats_table[safe_ids]                      # (T, 4)
+        pmfs = jax.vmap(lambda s: histogram.pattern_pmf(s, 1.0, G))(stats)
 
-    pmf_q = histogram.convolve_pmfs(pmfs, active)
-    e_qk = histogram.expected_order_statistic(pmf_q, n, jnp.float32(k), G)
+        pmf_q = histogram.convolve_pmfs(pmfs, active)
+        e_qk = histogram.expected_order_statistic(pmf_q, n, jnp.float32(k), G)
 
-    loo = leave_one_out_pmfs(pmfs, active)             # (T, T*G+1)
-    out_len = loo.shape[1]
+        loo = leave_one_out_pmfs(pmfs, active)             # (T, T*G+1)
+        out_len = loo.shape[1]
 
-    def per_relaxation(t, r):
-        pid = safe_ids[t]
-        rid = relax.ids[pid, r]
-        w = relax.weights[pid, r]
-        safe_rid = jnp.where(rid == PAD_KEY, 0, rid)
-        relaxed_pmf = histogram.pattern_pmf(stats_table[safe_rid], w, G)
-        pmf_qr = histogram.conv_truncate(loo[t], relaxed_pmf, out_len)
-        pmf_qr = pmf_qr / jnp.maximum(jnp.sum(pmf_qr), 1e-30)
-        e1 = histogram.expected_order_statistic(
-            pmf_qr, n_rel[t, r], jnp.float32(1.0), G)
-        usable = (rid != PAD_KEY) & active[t]
-        return jnp.where(usable, e1, -jnp.inf)
+        def per_relaxation(t, r):
+            pid = safe_ids[t]
+            rid = relax.ids[pid, r]
+            w = relax.weights[pid, r]
+            safe_rid = jnp.where(rid == PAD_KEY, 0, rid)
+            relaxed_pmf = histogram.pattern_pmf(stats_table[safe_rid], w, G)
+            pmf_qr = histogram.conv_truncate(loo[t], relaxed_pmf, out_len)
+            pmf_qr = pmf_qr / jnp.maximum(jnp.sum(pmf_qr), 1e-30)
+            e1 = histogram.expected_order_statistic(
+                pmf_qr, n_rel[t, r], jnp.float32(1.0), G)
+            usable = (rid != PAD_KEY) & active[t]
+            return jnp.where(usable, e1, -jnp.inf)
 
-    e_q1 = jax.vmap(lambda t: jax.vmap(lambda r: per_relaxation(t, r))(
-        jnp.arange(R)))(jnp.arange(T))
-    return e_qk, e_q1
+        e_q1 = jax.vmap(lambda t: jax.vmap(lambda r: per_relaxation(t, r))(
+            jnp.arange(R)))(jnp.arange(T))
+        return e_qk, e_q1
 
 
 def query_score_estimates(store: TripleStore, relax: RelaxTable,

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core.types import TripleStore, RelaxTable, PAD_KEY
 from repro.core import estimator
+from repro.core import scopes
 
 
 def plan_from_estimates(e_qk: jax.Array, e_q1: jax.Array,
@@ -51,14 +52,15 @@ def plan_from_estimates(e_qk: jax.Array, e_q1: jax.Array,
         ``E_Q'(1) ≥ E_Q(k) − s·(best_sibling − E_Q(k))`` — s=0 is the
         aggressive pure per-relaxation threshold, larger s is safer.
     """
-    promising = e_q1 > e_qk                               # (T, R)
-    speculate = promising.any(axis=1, keepdims=True) & active[:, None]
-    mask = speculate & (n_joinable > 0) & rel_exists
-    if sibling_slack is not None:
-        best = jnp.max(jnp.where(jnp.isfinite(e_q1), e_q1, -jnp.inf),
-                       axis=1, keepdims=True)
-        mask &= e_q1 >= e_qk - sibling_slack * (best - e_qk)
-    return mask
+    with jax.named_scope(scopes.ESTIMATE):
+        promising = e_q1 > e_qk                           # (T, R)
+        speculate = promising.any(axis=1, keepdims=True) & active[:, None]
+        mask = speculate & (n_joinable > 0) & rel_exists
+        if sibling_slack is not None:
+            best = jnp.max(jnp.where(jnp.isfinite(e_q1), e_q1, -jnp.inf),
+                           axis=1, keepdims=True)
+            mask &= e_q1 >= e_qk - sibling_slack * (best - e_qk)
+        return mask
 
 
 def plan(store: TripleStore, relax: RelaxTable, pattern_ids: jax.Array,

@@ -41,7 +41,9 @@ tests/test_refill.py, tests/test_executor_equiv.py).
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -53,6 +55,28 @@ import jax.numpy as jnp
 
 from repro.core import engine
 from repro.core.types import EngineConfig, PAD_KEY
+
+# The request numbers (``MicroBatcher.submit``'s ``r<n>``) of the group
+# being served, for the executor's host spans; empty off the MicroBatcher.
+_REQUESTS: contextvars.ContextVar[str] = contextvars.ContextVar(
+    "requests", default="")
+
+
+def _span(name: str, requests: str | None = None,
+          **meta) -> jax.profiler.TraceAnnotation:
+    """A host span in the profiler's trace, on the device trace's clock,
+    tagged with ``requests`` (default: those being served). Costs about
+    a microsecond when no trace is being taken. Metadata values are
+    integers or strings that do not start with a digit: the profiler
+    reads "1,2" as the number 1."""
+    if requests is None:
+        requests = _REQUESTS.get()
+    return jax.profiler.TraceAnnotation(name, requests=requests, **meta)
+
+
+def _requests(items) -> str:
+    """``"r3 r4"`` for MicroBatcher queue items (query, future, number)."""
+    return " ".join(f"r{n}" for _, _, n in items)
 
 
 def bucket_for(n: int, buckets: tuple[int, ...]) -> int:
@@ -148,11 +172,17 @@ class BatchStats:
     n_requests: int        # real requests
     q_bucket: int
     t_bucket: int
-    exec_s: float          # execute-phase wall time (plan_s separate)
+    # Execute-phase wall time, dispatch to ready. Where the call plans
+    # for itself (masks=None, the served path) it holds the planner's
+    # device time too: planning is dispatched without waiting.
+    exec_s: float
     n_iters: int           # batch lockstep trips (max over lanes)
     useful_iters: int      # sum over real lanes of per-lane n_iters
     wasted_iters: int      # sum over real lanes of per-lane n_wasted
-    plan_s: float = 0.0    # plan-phase wall time attributed to this batch
+    # Plan-phase wall time attributed to this batch: 0.0, since no call
+    # waits for a plan of its own (plan_group's waits are totalled in
+    # BatchExecutor.plan_total_s).
+    plan_s: float = 0.0
 
 
 class BatchExecutor:
@@ -229,12 +259,13 @@ class BatchExecutor:
 
     def _pad_group(self, group: list[np.ndarray], t_b: int,
                    q_b: int) -> jax.Array:
-        batch = np.full((q_b, t_b), int(PAD_KEY), np.int32)
-        for i, q in enumerate(group):
-            q = np.asarray(q, np.int32)
-            q = q[q != int(PAD_KEY)]
-            batch[i, :len(q)] = q
-        return jnp.asarray(batch)
+        with _span("executor.pad"):
+            batch = np.full((q_b, t_b), int(PAD_KEY), np.int32)
+            for i, q in enumerate(group):
+                q = np.asarray(q, np.int32)
+                q = q[q != int(PAD_KEY)]
+                batch[i, :len(q)] = q
+            return jnp.asarray(batch)
 
     def warmup(self, t_buckets: tuple[int, ...] | None = None) -> int:
         """Compile every (q_bucket, t_bucket) specialization; returns count.
@@ -317,42 +348,59 @@ class BatchExecutor:
             mask_b[i, :min(m.shape[0], t_b)] = m[:t_b]
         return jnp.asarray(mask_b)
 
+    def _plan(self, batch: jax.Array, masks: list[np.ndarray] | None,
+              q_b: int, t_b: int) -> jax.Array:
+        """The batch's (Q, T, R) plans: padded from ``plan_group``'s
+        ``masks``, or, when None (the served path), dispatched here
+        without waiting, so the planner's device time lands in the
+        execute call's wait."""
+        if masks is not None:
+            return self._mask_batch(masks, q_b, t_b)
+        with _span("planner.dispatch"):
+            return engine.plan_query_batch(self.store, self.relax, batch,
+                                           self.cfg, self.mode)
+
     def _finish_batch(self, res, group: list[np.ndarray], q_b: int,
-                      t_b: int, dt: float, plan_s: float,
-                      trips: int, wasted: int | None = None
+                      t_b: int, dt: float, lanes: int | None = None
                       ) -> list[ServedResult]:
         """Unpad per-request results + record stats (both exec paths).
 
-        ``wasted`` overrides the waste total: the refill path passes the
-        sum over ALL queue entries, because an idle lane's drain trips
-        are attributed to the last entry it served — which can be a pad
-        entry when the queue was padded to its depth bucket. Summing real
-        entries only (the fixed-batch rule, where a pad lane's frozen
-        trips are padding artifact, not real-lane waste) would silently
-        drop that genuine idle time."""
-        keys = np.asarray(res.keys)
-        scores = np.asarray(res.scores)
-        mask = np.asarray(res.relax_mask)
-        n_pulled = np.asarray(res.n_pulled)
-        n_answers = np.asarray(res.n_answers)
-        n_iters = np.asarray(res.n_iters)
-        n_wasted = np.asarray(res.n_wasted)
-        out = [ServedResult(
-            keys=keys[i], scores=scores[i],
-            n_pulled=int(n_pulled[i]), n_answers=int(n_answers[i]),
-            n_iters=int(n_iters[i]), n_wasted=int(n_wasted[i]),
-            relax_mask=mask[i, :self._true_t(q)],
-            batch_size=len(group)) for i, q in enumerate(group)]
+        ``lanes`` is None for a fixed batch, whose lockstep trips are the
+        slowest lane's and whose waste sums the real lanes: a pad lane's
+        frozen trips are padding artifact. A streaming call's trips are
+        its lane-trips (useful + idle, pad entries included) spread over
+        its ``lanes``, display-only, and its waste sums ALL queue
+        entries, because an idle lane's drain trips are attributed to
+        the last entry it served — which can be a pad entry when the
+        queue was padded to its depth bucket."""
+        with _span("executor.unpad"):
+            keys = np.asarray(res.keys)
+            scores = np.asarray(res.scores)
+            mask = np.asarray(res.relax_mask)
+            n_pulled = np.asarray(res.n_pulled)
+            n_answers = np.asarray(res.n_answers)
+            n_iters = np.asarray(res.n_iters)
+            n_wasted = np.asarray(res.n_wasted)
+            out = [ServedResult(
+                keys=keys[i], scores=scores[i],
+                n_pulled=int(n_pulled[i]), n_answers=int(n_answers[i]),
+                n_iters=int(n_iters[i]), n_wasted=int(n_wasted[i]),
+                relax_mask=mask[i, :self._true_t(q)],
+                batch_size=len(group)) for i, q in enumerate(group)]
         useful = int(n_iters[:len(group)].sum())
-        if wasted is None:
+        if lanes is None:
+            trips = int(n_iters.max())
             wasted = int(n_wasted[:len(group)].sum())
+        else:
+            wasted = int(n_wasted.sum())
+            trips = -(-(int(n_iters.sum()) + wasted) // lanes)
         with self._lock:
             self._useful_total += useful
             self._wasted_total += wasted
             self.stats.append(BatchStats(
                 n_requests=len(group), q_bucket=q_b, t_bucket=t_b,
                 exec_s=dt, n_iters=trips, useful_iters=useful,
-                wasted_iters=wasted, plan_s=plan_s))
+                wasted_iters=wasted))
             if len(self.stats) > self.stats_cap:
                 del self.stats[:-self.stats_cap]
         return out
@@ -376,23 +424,17 @@ class BatchExecutor:
                 f"group size {len(group)} not in [1, {self.bcfg.max_batch}]")
         t_b = self._t_bucket(max(self._true_t(q) for q in group))
         q_b = bucket_for(len(group), self.bcfg.q_buckets)
-        batch = self._pad_group(group, t_b, q_b)
-        plan_s = 0.0
-        if masks is None:
+        with _span("executor.call", depth=q_b, t_bucket=t_b, n=len(group)):
+            batch = self._pad_group(group, t_b, q_b)
+            mask_b = self._plan(batch, masks, q_b, t_b)
             t0 = time.perf_counter()
-            mask_b = engine.plan_query_batch(self.store, self.relax, batch,
-                                             self.cfg, self.mode)
-            plan_s = time.perf_counter() - t0
-        else:
-            mask_b = self._mask_batch(masks, q_b, t_b)
-        t0 = time.perf_counter()
-        res = engine.run_query_batch_with_masks(self.store, self.relax,
-                                                batch, mask_b, self.cfg)
-        jax.block_until_ready(res.scores)
-        dt = time.perf_counter() - t0
-        # Fixed-batch lockstep trips = the slowest lane's trip count.
-        trips = int(np.asarray(res.n_iters).max())
-        return self._finish_batch(res, group, q_b, t_b, dt, plan_s, trips)
+            with _span("executor.dispatch"):
+                res = engine.run_query_batch_with_masks(
+                    self.store, self.relax, batch, mask_b, self.cfg)
+            with _span("executor.wait"):
+                jax.block_until_ready(res.scores)
+            dt = time.perf_counter() - t0
+            return self._finish_batch(res, group, q_b, t_b, dt)
 
     def run_stream(self, group: list[np.ndarray],
                    masks: list[np.ndarray] | None = None
@@ -413,35 +455,22 @@ class BatchExecutor:
                 f"[1, {self.bcfg.refill_depth}]")
         t_b = self._t_bucket(max(self._true_t(q) for q in group))
         m_b = self._m_bucket(len(group))
-        batch = self._pad_group(group, t_b, m_b)
-        plan_s = 0.0
-        if masks is None:
-            t0 = time.perf_counter()
-            mask_b = engine.plan_query_batch(self.store, self.relax, batch,
-                                             self.cfg, self.mode)
-            plan_s = time.perf_counter() - t0
-        else:
-            mask_b = self._mask_batch(masks, m_b, t_b)
         # A lane beyond the queue depth would idle from trip one yet
         # still pay the vmapped step every trip — cap lanes at the padded
         # depth (static per jit shape, so this costs no extra compiles
         # beyond the (m_b, t_b) grid warmup already covers).
         lanes = min(self._lanes_n(), m_b)
-        t0 = time.perf_counter()
-        res = engine.run_query_stream_with_masks(
-            self.store, self.relax, batch, mask_b, self.cfg, lanes)
-        jax.block_until_ready(res.scores)
-        dt = time.perf_counter() - t0
-        # Streaming trip estimate: total lane-trips (useful + idle, pad
-        # entries included) spread over the lanes. Exact per-query
-        # counters live in the results; this is display-only.
-        it_all = np.asarray(res.n_iters)
-        w_all = np.asarray(res.n_wasted)
-        trips = int(-(-(int(it_all.sum()) + int(w_all.sum())) // lanes))
-        # Drain waste can be attributed to pad queue entries (the lane's
-        # last-served entry) — count every entry, not just real requests.
-        return self._finish_batch(res, group, m_b, t_b, dt, plan_s, trips,
-                                  wasted=int(w_all.sum()))
+        with _span("executor.call", depth=m_b, t_bucket=t_b, n=len(group)):
+            batch = self._pad_group(group, t_b, m_b)
+            mask_b = self._plan(batch, masks, m_b, t_b)
+            t0 = time.perf_counter()
+            with _span("executor.dispatch"):
+                res = engine.run_query_stream_with_masks(
+                    self.store, self.relax, batch, mask_b, self.cfg, lanes)
+            with _span("executor.wait"):
+                jax.block_until_ready(res.scores)
+            dt = time.perf_counter() - t0
+            return self._finish_batch(res, group, m_b, t_b, dt, lanes)
 
     def _exec_cap(self) -> int:
         return (self.bcfg.refill_depth if self.bcfg.refill
@@ -552,6 +581,10 @@ class MicroBatcher:
     the oldest has waited ``max_wait_s``. Flushed requests are grouped by
     T bucket (one executor call per group) so shape specializations are
     reused. Use as a context manager, or call ``close()``.
+
+    Every request gets a running number, ``r<n>`` in the metadata of the
+    host spans that serve it: ``frontend.collect`` (first request taken
+    to flush), ``frontend.flush`` and the executor's spans inside it.
     """
 
     _STOP = object()
@@ -561,6 +594,7 @@ class MicroBatcher:
         self._q: queue.Queue = queue.Queue()
         self._lock = threading.Lock()
         self._closed = False
+        self._numbers = itertools.count(1)
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -574,7 +608,8 @@ class MicroBatcher:
                 fut.set_exception(RuntimeError(
                     "MicroBatcher is closed; request rejected"))
                 return fut
-            self._q.put((np.asarray(query, np.int32), fut))
+            self._q.put((np.asarray(query, np.int32), fut,
+                         next(self._numbers)))
         return fut
 
     def __enter__(self):
@@ -606,20 +641,25 @@ class MicroBatcher:
             if item is self._STOP:
                 self._drain_and_exit([])
                 return
-            pending = [item]
-            deadline = time.perf_counter() + bcfg.max_wait_s
-            while len(pending) < bcfg.max_batch:
-                left = deadline - time.perf_counter()
-                if left <= 0:
-                    break
-                try:
-                    nxt = self._q.get(timeout=left)
-                except queue.Empty:
-                    break
-                if nxt is self._STOP:
-                    self._drain_and_exit(pending)
-                    return
-                pending.append(nxt)
+            pending, stop = [item], False
+            with jax.profiler.TraceAnnotation("frontend.collect") as span:
+                deadline = time.perf_counter() + bcfg.max_wait_s
+                while len(pending) < bcfg.max_batch:
+                    left = deadline - time.perf_counter()
+                    if left <= 0:
+                        break
+                    try:
+                        nxt = self._q.get(timeout=left)
+                    except queue.Empty:
+                        break
+                    if nxt is self._STOP:
+                        stop = True
+                        break
+                    pending.append(nxt)
+                span.set_metadata(requests=_requests(pending))
+            if stop:
+                self._drain_and_exit(pending)
+                return
             self._flush(pending)
 
     def _drain_and_exit(self, pending):
@@ -645,20 +685,25 @@ class MicroBatcher:
         still resolve."""
         if not pending:
             return
-        by_bucket: dict[int, list[tuple[np.ndarray, Future]]] = {}
-        for q, fut in pending:
-            try:
-                t_b = self.executor._t_bucket(self.executor._true_t(q))
-            except Exception as e:  # noqa: BLE001 — fail the request only
-                fut.set_exception(e)
-                continue
-            by_bucket.setdefault(t_b, []).append((q, fut))
-        for _, items in sorted(by_bucket.items()):
-            try:
-                results = self.executor.run_batch([q for q, _ in items])
-                for (_, fut), r in zip(items, results):
-                    fut.set_result(r)
-            except Exception as e:  # noqa: BLE001 — fail the batch, not the server
-                for _, fut in items:
-                    if not fut.done():
-                        fut.set_exception(e)
+        with _span("frontend.flush", _requests(pending)):
+            by_bucket: dict[int, list[tuple[np.ndarray, Future, int]]] = {}
+            for q, fut, n in pending:
+                try:
+                    t_b = self.executor._t_bucket(self.executor._true_t(q))
+                except Exception as e:  # noqa: BLE001 — fail the request only
+                    fut.set_exception(e)
+                    continue
+                by_bucket.setdefault(t_b, []).append((q, fut, n))
+            for _, items in sorted(by_bucket.items()):
+                token = _REQUESTS.set(_requests(items))
+                try:
+                    results = self.executor.run_batch(
+                        [q for q, _, _ in items])
+                    for (_, fut, _), r in zip(items, results):
+                        fut.set_result(r)
+                except Exception as e:  # noqa: BLE001 — fail the batch, not the server
+                    for _, fut, _ in items:
+                        if not fut.done():
+                            fut.set_exception(e)
+                finally:
+                    _REQUESTS.reset(token)
