@@ -200,8 +200,9 @@ def fig6to9_efficiency(results_by_ds):
 def planner_cost(fast: bool = False):
     """Planner-cost scaling: plan time vs execute time, exact vs sketch.
 
-    The exact planner's binary-search cardinalities cost O(T·R·L·log L)
-    per query; the sketched planner is O(T·R·W), independent of L. This
+    The exact planner's cardinalities cost O(T·R·L·log L) per query by
+    binary search, or O(T·R·domain/32) by popcount where the store holds
+    key bitmaps; the sketched planner is O(T·R·W), independent of L. This
     table makes the scaling visible (and reports the (T, R) mask agreement
     between the two at each L — the sketch's planning-quality check).
     """

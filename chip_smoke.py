@@ -42,7 +42,7 @@ from repro.launch.mesh import make_mesh  # noqa: E402
 # fan-out; enough entities that no list holds more than ~3% of them.
 LIST_LEN = kg_specqp.L_SHARD
 N_RELAX = kg_specqp.N_RELAX
-N_ENTITIES = 250_000
+N_ENTITIES = kg_specqp.N_ENTITIES
 ENGINE = kg_specqp.ENGINE
 N_SEQUENTIAL = 8        # queries answered one at a time (trinit + specqp)
 N_SERVED = 32           # queries through the refill BatchExecutor

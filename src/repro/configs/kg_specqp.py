@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 from repro import sharding
 from repro.configs import base
 from repro.core import distributed as dist
-from repro.core import sketches
+from repro.core import kg, sketches
 from repro.core.types import TripleStore, RelaxTable, EngineConfig
 
 ARCH = "kg-specqp"
@@ -29,6 +29,7 @@ SKIP_SHAPES: dict[str, str] = {}
 # Production store geometry (per shard): P patterns × L_shard items.
 N_PATTERNS = 1024
 L_SHARD = 8192
+N_ENTITIES = 250_000      # entities (the key domain) per shard
 N_RELAX = 10
 N_QUERIES = 32
 T_MAX = 4
@@ -60,6 +61,10 @@ def store_specs(n_shards: int):
         # saturate the old fixed 1024-word default).
         sketch=base.spec((n_shards, Pn, sketches.SKETCH_LANES,
                           sketches.adaptive_words(L_SHARD)), jnp.uint32),
+        # Exact planner's key bitmaps at the width the sharded ingest
+        # gives: global key domain, global list length.
+        key_bits=base.spec((n_shards, Pn, kg.bitmap_words(
+            n_shards * N_ENTITIES, n_shards * L_SHARD)), jnp.uint32),
     )
     relax = RelaxTable(ids=base.spec((Pn, N_RELAX), i32),
                        weights=base.spec((Pn, N_RELAX), f32))
@@ -83,7 +88,8 @@ def make_cell(shape: str) -> base.CellSpec:
         keys=("all_devices", None, None), scores=("all_devices", None, None),
         lengths=("all_devices", None), sorted_keys=("all_devices", None, None),
         stats=("all_devices", None, None),
-        sketch=("all_devices", None, None, None))
+        sketch=("all_devices", None, None, None),
+        key_bits=("all_devices", None, None))
     relax_axes = RelaxTable(ids=(None, None), weights=(None, None))
     return base.CellSpec(ARCH, shape, "serve", fn,
                          (stores, relax, gstats, queries),

@@ -89,8 +89,11 @@ def shard_workload(pattern_lists, n_shards: int,
     # list: shard stores stack into a single (S, P, ...) pytree and their
     # sketch estimates psum, so per-shard adaptive widths (which would
     # differ under hash skew) are not an option here.
-    sketch_words = sketchlib.adaptive_words(
-        max((len(k) for k, _ in pattern_lists), default=1))
+    longest = max((len(k) for k, _ in pattern_lists), default=1)
+    sketch_words = sketchlib.adaptive_words(longest)
+    # Likewise one key-bitmap width, the one the unsharded lists get: keys
+    # keep their global ids on every shard.
+    key_words = kglib.key_words_for([k for k, _ in pattern_lists], longest)
     shard_stores = []
     for s_id in range(n_shards):
         per_pattern = []
@@ -99,7 +102,7 @@ def shard_workload(pattern_lists, n_shards: int,
             per_pattern.append((k[sel].astype(np.int32), sn[sel]))
         shard_stores.append(kglib.build_store_host(
             per_pattern, list_len=list_len, normalize=False,
-            sketch_words=sketch_words))
+            sketch_words=sketch_words, key_words=key_words))
 
     stores = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *shard_stores)
     return stores, g_stats

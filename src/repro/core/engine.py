@@ -415,6 +415,17 @@ def plan_for_mode(store: TripleStore, relax: RelaxTable,
     raise ValueError(mode)
 
 
+def plans_by_bitmap(store: TripleStore, cfg: EngineConfig,
+                    mode: str) -> bool:
+    """Whether ``plan_for_mode`` counts its joins by popcount over the
+    store's key bitmaps: the exact planner of a Spec-QP mode on a store
+    that holds them. False for the binary-search fallback, sketch mode and
+    the modes that plan nothing."""
+    return (mode in ("specqp", "specqp_pattern")
+            and cfg.cardinality_mode == "exact"
+            and store.key_bits.shape[-1] > 0)
+
+
 @partial(jax.jit, static_argnames=("cfg", "mode"))
 def run_query(store: TripleStore, relax: RelaxTable, pattern_ids: jax.Array,
               cfg: EngineConfig, mode: str = "specqp") -> EngineResult:

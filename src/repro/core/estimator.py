@@ -5,14 +5,18 @@ Cardinalities come in two interchangeable flavors behind the
 
 * ``"exact"`` — exact join selectivities like the paper (footnote 3): for
   star joins on a shared variable the join cardinality is the size of the
-  intersection of the per-pattern key sets, computed with vectorized
-  binary searches over the key-sorted copies kept in the store
-  (O(L log L) per probe).
+  intersection of the per-pattern key sets. Where the store holds key
+  bitmaps (``TripleStore.key_bits``, sized at ingest from the key domain)
+  every count is a popcount of ANDs and ORs of whole rows, O(domain / 32)
+  word operations per count; where it does not (a domain too wide for
+  the lists), vectorized binary searches over the key-sorted copies
+  (O(L log L) per count). Both give the same integers.
 * ``"sketch"`` — bitmap-signature estimates (sketches.py, DESIGN.md §6):
   O(W) bitwise popcounts per probe, planning cost independent of L.
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -20,6 +24,62 @@ from repro.core.types import TripleStore, RelaxTable, PAD_KEY, KEY_SENTINEL
 from repro.core import histogram
 from repro.core import scopes
 from repro.core import sketches
+
+
+_ALL = np.uint32(0xFFFFFFFF)
+
+
+def _popcount(words: jax.Array) -> jax.Array:
+    """(..., W) uint32 → (...) f32 number of set bits."""
+    return jnp.sum(jax.lax.population_count(words), axis=-1,
+                   dtype=jnp.int32).astype(jnp.float32)
+
+
+def _source_bits(store: TripleStore, relax: RelaxTable,
+                 pattern_ids: jax.Array):
+    """(T, R+1, Wk) key bitmaps of each query pattern (slot 0) and its
+    relaxations, and (T, R+1) which of them are real (PAD slots read row
+    0 and are masked by the caller)."""
+    safe = jnp.where(pattern_ids == PAD_KEY, 0, pattern_ids)
+    rel = relax.ids[safe]                                  # (T, R)
+    real = jnp.concatenate(
+        [jnp.ones((safe.shape[0], 1), bool), rel != PAD_KEY], axis=1)
+    ids = jnp.concatenate([safe[:, None], rel], axis=1)
+    return store.key_bits[jnp.where(real, ids, 0)], real
+
+
+def _and_others(rows: jax.Array) -> jax.Array:
+    """(T, W) → (T, W): row t is the AND of every row u ≠ t (all ones for
+    T = 1); the u = t term is masked to all ones, not computed."""
+    others = ~jnp.eye(rows.shape[0], dtype=bool)
+    return jax.lax.reduce(jnp.where(others[:, :, None], rows[None], _ALL),
+                          _ALL, jax.lax.bitwise_and, (1,))
+
+
+def _bitmap_cardinalities(store: TripleStore, relax: RelaxTable,
+                          pattern_ids: jax.Array, active: jax.Array):
+    """``exact_cardinalities`` by popcount: an inactive pattern contributes
+    an all-ones row to the ANDs, a PAD relaxation slot counts 0."""
+    rows, real = _source_bits(store, relax, pattern_ids)
+    pats = jnp.where(active[:, None], rows[:, 0], _ALL)    # (T, Wk)
+    every = jax.lax.reduce(pats, _ALL, jax.lax.bitwise_and, (0,))
+    n = jnp.where(active[0], _popcount(every), 0.0)
+    n_rel = _popcount(rows[:, 1:] & _and_others(pats)[:, None])
+    return n, jnp.where(real[:, 1:], n_rel, 0.0)
+
+
+def _bitmap_joinable_counts(store: TripleStore, relax: RelaxTable,
+                            pattern_ids: jax.Array,
+                            active: jax.Array) -> jax.Array:
+    """``joinable_counts`` by popcount: each pattern's sources are OR'd
+    into one row (PAD slots contribute zeros), inactive patterns give an
+    all-ones row."""
+    rows, real = _source_bits(store, relax, pattern_ids)
+    union = jax.lax.reduce(jnp.where(real[:, :, None], rows, np.uint32(0)),
+                           np.uint32(0), jax.lax.bitwise_or, (1,))
+    union = jnp.where(active[:, None], union, _ALL)        # (T, Wk)
+    n_join = _popcount(rows[:, 1:] & _and_others(union)[:, None])
+    return jnp.where(real[:, 1:], n_join, 0.0)
 
 
 def member(sorted_keys: jax.Array, probes: jax.Array) -> jax.Array:
@@ -85,6 +145,8 @@ def joinable_counts(store: TripleStore, relax: RelaxTable,
     it without any loss. Local counts ``psum`` to global under hash
     partitioning, like the exact cardinalities.
     """
+    if store.key_bits.shape[-1] > 0:     # static: kg.bitmap_words
+        return _bitmap_joinable_counts(store, relax, pattern_ids, active)
     T = pattern_ids.shape[0]
     R = relax.ids.shape[1]
     safe_ids = jnp.where(pattern_ids == PAD_KEY, 0, pattern_ids)
@@ -125,6 +187,8 @@ def exact_cardinalities(store: TripleStore, relax: RelaxTable,
     global cardinality is the ``psum`` of per-shard values (a key's triples
     for every pattern live on one shard).
     """
+    if store.key_bits.shape[-1] > 0:     # static: kg.bitmap_words
+        return _bitmap_cardinalities(store, relax, pattern_ids, active)
     T = pattern_ids.shape[0]
     R = relax.ids.shape[1]
     safe_ids = jnp.where(pattern_ids == PAD_KEY, 0, pattern_ids)

@@ -40,22 +40,67 @@ def compute_pattern_stats(scores: np.ndarray, length: int) -> np.ndarray:
     return np.array([m, sigma_r, S_r, total], dtype=np.float32)
 
 
+def bitmap_words(domain: int, list_len: int) -> int:
+    """uint32 words of a key bitmap row over keys ``[0, domain)``.
+
+    ⌈domain / 32⌉ rounded up to a multiple of 128 (a TPU lane row), or 0
+    where the row would hold more bytes than a pattern's list data in the
+    store (4·Wk > 12·L: keys, scores and sorted keys), i.e. roughly where
+    the domain is wider than 96·L. A zero width leaves the exact planner
+    on binary search over ``sorted_keys``.
+    """
+    words = -(-max(domain, 0) // 32)
+    words = -(-words // 128) * 128
+    return 0 if 4 * words > 12 * list_len else words
+
+
+def key_words_for(pattern_keys, list_len: int) -> int:
+    """``bitmap_words`` for these lists: the domain runs to the largest key.
+    Lists with a negative key, or none at all, get no bitmap."""
+    ks = [np.asarray(k) for k in pattern_keys if len(k)]
+    if not ks or min(int(k.min()) for k in ks) < 0:
+        return 0
+    return bitmap_words(max(int(k.max()) for k in ks) + 1, list_len)
+
+
+def build_key_bits(keys: np.ndarray, words: int) -> np.ndarray:
+    """(P, words) uint32 key bitmaps of a (P, L) ``PAD_KEY``-padded key
+    array: bit ``k % 32`` of word ``k // 32`` in row p is set iff key k is
+    in row p."""
+    P = keys.shape[0]
+    if words == 0:
+        return np.zeros((P, 0), np.uint32)
+    rows, cols = np.nonzero(keys != int(PAD_KEY))
+    k = keys[rows, cols].astype(np.int64)
+    if len(k) and (k.min() < 0 or k.max() >= 32 * words):
+        raise ValueError(f"keys in [{k.min()}, {k.max()}] do not fit "
+                         f"{words} bitmap words")
+    # Keys are unique within a list, so no bit of a word is set twice and
+    # the sum of the bits is their OR (exact in float64 below 2**53).
+    acc = np.bincount(rows * words + (k >> 5),
+                      weights=np.left_shift(1, k & 31).astype(np.float64),
+                      minlength=P * words)
+    return acc.astype(np.uint32).reshape(P, words)
+
+
 def build_store(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
                 list_len: int | None = None,
                 normalize: bool = True,
                 sketch_lanes: int = sketchlib.SKETCH_LANES,
-                sketch_words: int | None = None) -> TripleStore:
+                sketch_words: int | None = None,
+                key_words: int | None = None) -> TripleStore:
     """``build_store_host`` placed on the default device."""
     return jax.tree_util.tree_map(
         jnp.asarray, build_store_host(pattern_lists, list_len, normalize,
-                                      sketch_lanes, sketch_words))
+                                      sketch_lanes, sketch_words, key_words))
 
 
 def build_store_host(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
                      list_len: int | None = None,
                      normalize: bool = True,
                      sketch_lanes: int = sketchlib.SKETCH_LANES,
-                     sketch_words: int | None = None) -> TripleStore:
+                     sketch_words: int | None = None,
+                     key_words: int | None = None) -> TripleStore:
     """Build a host (numpy) TripleStore from per-pattern (keys, raw_scores).
 
     Scores are normalized per Definition 5 (divide by the list max) unless
@@ -73,7 +118,11 @@ def build_store_host(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
     built unconditionally (also for exact-mode users): the one-time host
     cost is small next to the sort/stats pass, and a store carrying
     signatures can serve either ``cardinality_mode`` per query without
-    re-ingest.
+    re-ingest. The exact planner's key bitmaps (``key_bits``) are sized
+    from the data by default (``key_words_for``: zero-width where the key
+    domain is too wide for the lists); an explicit ``key_words`` pins the
+    width (the sharded build passes the global one, so shard stores
+    stack) and must hold every key.
     """
     P = len(pattern_lists)
     if list_len is None:
@@ -112,9 +161,12 @@ def build_store_host(pattern_lists: list[tuple[np.ndarray, np.ndarray]],
             max((len(k) for k, _ in pattern_lists), default=1))
     sketch = sketchlib.build_sketches([k for k, _ in pattern_lists],
                                       lanes=sketch_lanes, words=sketch_words)
+    if key_words is None:
+        key_words = key_words_for([k for k, _ in pattern_lists], list_len)
     return TripleStore(keys=keys, scores=scores, lengths=lengths,
                        sorted_keys=sorted_keys, stats=stats,
-                       sketch=sketch)
+                       sketch=sketch,
+                       key_bits=build_key_bits(keys, key_words))
 
 
 def build_relax_table(P: int,

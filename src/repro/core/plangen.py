@@ -74,8 +74,9 @@ def plan(store: TripleStore, relax: RelaxTable, pattern_ids: jax.Array,
       k: top-k target (static).
       G: histogram grid bins per unit score (static).
       sibling_slack: see ``plan_from_estimates``.
-      cardinality_mode: "exact" (binary-search selectivities, cost grows
-        with L) or "sketch" (bitmap-signature estimates, L-independent).
+      cardinality_mode: "exact" (true selectivities: popcount over the
+        store's key bitmaps, or binary search where it has none) or
+        "sketch" (bitmap-signature estimates, L-independent).
 
     Returns:
       (T, R) bool — True where relaxation r of pattern t must be processed.

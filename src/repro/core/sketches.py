@@ -1,10 +1,11 @@
 """Sketched cardinalities: bitmap key signatures for O(W) planner probes.
 
 The exact planner (``estimator.exact_cardinalities``) answers every
-"how many keys do these lists share" question with binary searches over
-full posting lists, so planning cost grows with the list length L. This
-module trades a bounded relative error for planning cost *independent of
-L* (DESIGN.md §6):
+"how many keys do these lists share" question by popcount over key
+bitmaps as wide as the key domain, or, where the domain is too wide for
+the lists, by binary searches over full posting lists, so its cost grows
+with the domain or with L. This module trades a bounded relative error
+for planning cost *independent of both* (DESIGN.md §6):
 
 * **Ingest** — every pattern gets a fixed-width signature of ``LANES``
   independent bitmap lanes, each ``W`` uint32 words (m = 32·W bits). A key

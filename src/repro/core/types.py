@@ -52,6 +52,12 @@ class TripleStore:
     ``(m, sigma_r, S_r, S_m)`` (§3.1.1).
     ``sketch`` holds fixed-width bitmap key signatures (DESIGN.md §6) for
     the sketched cardinality planner; its width is independent of L.
+    ``key_bits`` holds each pattern's exact key set as a bitmap over the key
+    domain: bit ``k % 32`` of word ``k // 32`` is set iff key ``k`` is in
+    the list. The exact planner counts by AND/OR and popcount over it; it
+    is zero-width (P, 0) where the domain is too wide for the lists
+    (``kg.bitmap_words``), and the planner then binary-searches
+    ``sorted_keys``.
     """
 
     keys: jax.Array          # (P, L) int32, PAD_KEY padded
@@ -60,6 +66,7 @@ class TripleStore:
     sorted_keys: jax.Array   # (P, L) int32 ascending, KEY_SENTINEL padded
     stats: jax.Array         # (P, 4) f32: m, sigma_r, S_r, S_m
     sketch: jax.Array        # (P, LANES, W) uint32 bitmap signatures
+    key_bits: jax.Array      # (P, Wk) uint32 exact key bitmaps, Wk may be 0
 
 
 @_pytree
@@ -103,9 +110,10 @@ class EngineConfig:
     # joinable relaxation of a speculated pattern; a float s adds the
     # E_Q'(1) margin test (0 = most aggressive). See plangen.plan.
     plan_slack: float | None = None
-    # How the planner prices joins: "exact" binary-searches full posting
-    # lists (O(L log L) per probe, the paper's footnote-3 oracle); "sketch"
-    # uses the bitmap signatures (O(W) per probe, L-independent — see
+    # How the planner prices joins: "exact" counts the true intersections
+    # (the paper's footnote-3 oracle) by popcount over the store's key
+    # bitmaps, or by binary search where the store has none; "sketch" uses
+    # the bitmap signatures (O(W) per probe, L-independent — see
     # sketches.py / DESIGN.md §6).
     cardinality_mode: str = "exact"
     # Probe seen rings with the Pallas rank-join kernel: compiled on a TPU,

@@ -163,6 +163,7 @@ class ServedResult:
     n_wasted: int          # lockstep trips this lane sat frozen
     relax_mask: np.ndarray  # (T, R) for the request's true T
     batch_size: int        # real requests in the micro-batch served with
+    planned_by_bitmap: bool  # plan counted by popcount over key bitmaps
 
 
 @dataclasses.dataclass
@@ -218,6 +219,7 @@ class BatchExecutor:
         # Host-side copies for the work scheduler (batch composition).
         self._lengths = np.asarray(store.lengths)
         self._rel_ids = np.asarray(relax.ids)
+        self._by_bitmap = engine.plans_by_bitmap(store, cfg, mode)
 
     def reset_stats(self) -> None:
         with self._lock:
@@ -386,7 +388,8 @@ class BatchExecutor:
                 n_pulled=int(n_pulled[i]), n_answers=int(n_answers[i]),
                 n_iters=int(n_iters[i]), n_wasted=int(n_wasted[i]),
                 relax_mask=mask[i, :self._true_t(q)],
-                batch_size=len(group)) for i, q in enumerate(group)]
+                batch_size=len(group), planned_by_bitmap=self._by_bitmap)
+                for i, q in enumerate(group)]
         useful = int(n_iters[:len(group)].sum())
         if lanes is None:
             trips = int(n_iters.max())

@@ -28,6 +28,8 @@ for p in range(P):
 mesh = make_mesh((4, 2), ("data", "model"))
 skg = distributed.build_sharded_kg(lists, wl.relax, 8, mesh=mesh)
 assert len({s.device for s in skg.stores.keys.addressable_shards}) == 8
+# Every shard plans from key bitmaps at the unsharded store's width.
+assert skg.stores.key_bits.shape[-1] == wl.store.key_bits.shape[-1] > 0
 cfg = EngineConfig(block=8, k=5, grid_bins=128)
 for i in range(len(wl.queries)):
     q = jnp.asarray(wl.queries[i])
@@ -83,6 +85,56 @@ def test_shard_workload_survives_hash_skew():
     # Every key survived the round-trip onto shard 0.
     keys0 = np.asarray(stores.keys)[0, 0]
     assert set(keys0[keys0 >= 0].tolist()) == set(hot.tolist())
+
+
+def test_sharded_key_bits_plan_like_one_store():
+    """Shard stores built with the global ``key_words`` split the single
+    store's key bitmaps, their local exact counts sum to its counts, and
+    the plan from the summed counts is the single-device plan."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import distributed, engine, estimator, plangen
+    from repro.core.types import EngineConfig, PAD_KEY
+    from repro.data import kg_synth
+
+    wl = kg_synth.tiny_workload(seed=3, n_queries=4, n_entities=384,
+                                list_len=48)
+    P = wl.store.keys.shape[0]
+    lists = [(np.asarray(wl.store.keys[p][:int(wl.store.lengths[p])]),
+              np.asarray(wl.store.scores[p][:int(wl.store.lengths[p])]))
+             for p in range(P)]
+    stores, g_stats = distributed.shard_workload(lists, 4)
+    bits = np.asarray(stores.key_bits)                       # (S, P, Wk)
+    assert bits.shape[1:] == wl.store.key_bits.shape and bits.shape[2] > 0
+    np.testing.assert_array_equal(np.bitwise_or.reduce(bits, axis=0),
+                                  np.asarray(wl.store.key_bits))
+    assert not np.any(bits[0] & bits[1])                     # disjoint keys
+    cfg = EngineConfig(block=8, k=5, grid_bins=128)
+
+    @jax.jit
+    def counts(store, q):
+        active = q != PAD_KEY
+        n, n_rel = estimator.exact_cardinalities(store, wl.relax, q, active)
+        return n, n_rel, estimator.joinable_counts(store, wl.relax, q,
+                                                   active)
+
+    for q in map(jnp.asarray, wl.queries):
+        local = [counts(jax.tree_util.tree_map(lambda x: x[s], stores), q)
+                 for s in range(4)]
+        n, n_rel, n_join = (sum(c[i] for c in local) for i in range(3))
+        for a, b in zip((n, n_rel, n_join), counts(wl.store, q)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        active = q != PAD_KEY
+        e_qk, e_q1 = estimator.score_estimates_from_cards(
+            jnp.asarray(g_stats), wl.relax, q, active, n, n_rel, cfg.k,
+            cfg.grid_bins)
+        rel_exists = wl.relax.ids[jnp.where(active, q, 0)] != PAD_KEY
+        mask = plangen.plan_from_estimates(e_qk, e_q1, n_join, rel_exists,
+                                           active, cfg.plan_slack)
+        np.testing.assert_array_equal(
+            np.asarray(mask), np.asarray(engine.plan_for_mode(
+                wl.store, wl.relax, q, cfg, "specqp")))
 
 
 @pytest.mark.slow

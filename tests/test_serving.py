@@ -123,6 +123,38 @@ def test_offline_executor_equivalence(mode):
     assert 0.0 <= ex.wasted_fraction() < 1.0
 
 
+@pytest.mark.parametrize("mode,cardinality,key_bits,expect", [
+    ("specqp", "exact", True, True),
+    ("specqp", "exact", False, False),      # binary-search fallback
+    ("specqp", "sketch", True, False),
+    ("trinit", "exact", True, False),       # plans nothing
+])
+def test_served_result_reports_bitmap_planning(mode, cardinality, key_bits,
+                                               expect):
+    """``ServedResult.planned_by_bitmap`` is true exactly where the exact
+    planner counted by popcount over the store's key bitmaps; the plans
+    of the two exact representations are the same bits."""
+    import dataclasses
+    wl = small_workload(seed=0, n_queries=4)
+    assert wl.store.key_bits.shape[-1] > 0
+    store = wl.store if key_bits else dataclasses.replace(
+        wl.store, key_bits=jnp.zeros((wl.store.keys.shape[0], 0), jnp.uint32))
+    cfg = dataclasses.replace(CFG, cardinality_mode=cardinality)
+    bcfg = batching.BatchingConfig(max_batch=4, max_wait_s=0.01,
+                                   q_buckets=(1, 4), t_buckets=(2, 3))
+    queries = [np.asarray(q) for q in wl.queries]
+    results = batching.BatchExecutor(store, wl.relax, cfg, mode,
+                                     bcfg).run(queries)
+    assert [r.planned_by_bitmap for r in results] == [expect] * len(queries)
+    if cardinality == "exact":
+        for r, q in zip(results, queries):
+            s = engine.run_query(wl.store, wl.relax, jnp.asarray(q), CFG,
+                                 mode)
+            T = int((q != int(PAD_KEY)).sum())
+            np.testing.assert_array_equal(r.relax_mask,
+                                          np.asarray(s.relax_mask)[:T])
+
+
 @settings(max_examples=5, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=3),
        n=st.integers(min_value=1, max_value=7),

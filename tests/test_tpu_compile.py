@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import kg_specqp
-from repro.core import engine, sketches
+from repro.core import engine, kg, sketches
 from repro.core.types import RelaxTable, TripleStore
 from repro.kernels import ops as kops
 from repro.kernels import rank_join
@@ -65,7 +65,9 @@ def _store_relax(sharding):
         sorted_keys=_spec(sharding, (Pn, L), i32),
         stats=_spec(sharding, (Pn, 4), f32),
         sketch=_spec(sharding, (Pn, sketches.SKETCH_LANES,
-                                sketches.adaptive_words(L)), jnp.uint32))
+                                sketches.adaptive_words(L)), jnp.uint32),
+        key_bits=_spec(sharding, (Pn, kg.bitmap_words(
+            kg_specqp.N_ENTITIES, L)), jnp.uint32))
     relax = RelaxTable(ids=_spec(sharding, (Pn, R), i32),
                        weights=_spec(sharding, (Pn, R), f32))
     return store, relax
@@ -113,4 +115,16 @@ def test_refill_stream_fits_one_v5e(one_chip):
     compiled = engine.run_query_stream.lower(
         store, relax, _spec(one_chip, (DEPTH, T), jnp.int32),
         cfg=kg_specqp.ENGINE, mode="specqp", lanes=LANES).compile()
+    assert _bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_batched_exact_planner_fits_one_v5e(one_chip):
+    """The exact planner over a 64-deep queue of 4-pattern queries counts
+    from the key bitmaps (a popcount in the program) and, with the store,
+    fits one chip's HBM."""
+    store, relax = _store_relax(one_chip)
+    compiled = engine.plan_query_batch.lower(
+        store, relax, _spec(one_chip, (DEPTH, T), jnp.int32),
+        cfg=kg_specqp.ENGINE, mode="specqp").compile()
+    assert "popcnt" in compiled.as_text()
     assert _bytes(compiled) < V5E_HBM_BYTES
