@@ -75,8 +75,42 @@ def _init_state(T: int, R1: int, N: int, k: int) -> _LoopState:
         n_iters=jnp.int32(0), n_wasted=jnp.int32(0), done=jnp.array(False))
 
 
-def _step(streams: ops.MergedStreams, st: _LoopState, cfg: EngineConfig,
-          N: int) -> _LoopState:
+def _append_block(seen_keys: jax.Array, seen_scores: jax.Array,
+                  seen_cnt: jax.Array, t_star: jax.Array, blk_k: jax.Array,
+                  blk_s: jax.Array):
+    """Append one B-item block to stream ``t_star``'s seen ring.
+
+    seen_keys/seen_scores: (T, N); seen_cnt: (T,); blk_k/blk_s: (B,).
+    Returns the updated (seen_keys, seen_scores, seen_cnt). A NEG_INF
+    score (a PAD or dropped slot) is stored as 0.
+
+    N is a multiple of B and every append adds B items (``_seen_size``),
+    so the write position ``seen_cnt % N`` is always block-aligned: the
+    append replaces exactly one whole block of the (T, N/B, B) view, in
+    row ``t_star`` only (once the ring wraps under a ``seen_cap``, the
+    oldest block). It is written as one select over that view: T·N
+    element selects, with no reduction and no scatter. A
+    ``dynamic_update_slice`` would be a scatter under the executor's lane
+    vmap, which the CPU backend runs as a scalar loop.
+    """
+    T, N = seen_keys.shape
+    B = blk_k.shape[0]
+    rows = jnp.arange(T) == t_star                                  # (T,)
+    start_blk = (seen_cnt % jnp.int32(N)) // B                      # (T,)
+    hit = rows[:, None] & (jnp.arange(N // B)[None, :]
+                           == start_blk[:, None])                   # (T, N/B)
+    hit = hit[:, :, None]
+    blk_s = jnp.where(blk_s == NEG_INF, 0.0, blk_s)
+
+    def write(ring, blk):
+        return jnp.where(hit, blk, ring.reshape(T, N // B, B)).reshape(T, N)
+
+    return (write(seen_keys, blk_k), write(seen_scores, blk_s),
+            seen_cnt + jnp.where(rows, B, 0).astype(jnp.int32))
+
+
+def _step(streams: ops.MergedStreams, st: _LoopState,
+          cfg: EngineConfig) -> _LoopState:
     """One pull-join-bound iteration of the rank join for ONE query.
 
     This is THE loop body: every entry point (single query, fixed batch,
@@ -129,36 +163,11 @@ def _step(streams: ops.MergedStreams, st: _LoopState, cfg: EngineConfig,
         top_keys, top_scores = ops.topk_insert(
             st.top_keys, st.top_scores, cand_keys, cand_scores, k)
 
-    # Append the block to t*'s seen buffer (fixed B slots per pull;
-    # wraps as a ring when a seen_cap is configured). N is a multiple
-    # of B, so start is always block-aligned and start + B <= N. The
-    # append is a one-hot mask-and-reduce rather than a
-    # dynamic_update_slice because _step always runs under the unified
-    # executor's lane vmap, and a slice update with per-lane starts
-    # lowers to an XLA scatter that the CPU backend runs as a scalar
-    # loop under vmap.
+    # Append the block to t*'s seen ring (B slots per pull; it wraps when
+    # a seen_cap is configured) as a select of one whole block.
     with jax.named_scope(scopes.APPEND):
-        blk_s_store = jnp.where(blk_s == NEG_INF, 0.0, blk_s)
-
-        def append(t):
-            start = st.seen_cnt[t] % jnp.int32(N)
-            rel = jnp.arange(N) - start                    # (N,)
-            oh = rel[:, None] == jnp.arange(B)[None, :]    # (N, B)
-            in_win = (rel >= 0) & (rel < B)
-            upd_k = jnp.where(
-                in_win,
-                jnp.sum(jnp.where(oh, blk_k[None, :], 0), axis=1),
-                st.seen_keys[t])
-            upd_s = jnp.where(
-                in_win,
-                jnp.sum(jnp.where(oh, blk_s_store[None, :], 0.0), axis=1),
-                st.seen_scores[t])
-            sel = t == t_star
-            return (jnp.where(sel, upd_k, st.seen_keys[t]),
-                    jnp.where(sel, upd_s, st.seen_scores[t]))
-        seen_keys, seen_scores = jax.vmap(append)(jnp.arange(T))
-        seen_cnt = st.seen_cnt + jnp.where(
-            jnp.arange(T) == t_star, B, 0).astype(jnp.int32)
+        seen_keys, seen_scores, seen_cnt = _append_block(
+            st.seen_keys, st.seen_scores, st.seen_cnt, t_star, blk_k, blk_s)
         cursors = jax.vmap(
             lambda t, nc: jnp.where(t == t_star, nc, st.cursors[t]),
             in_axes=(0, None))(jnp.arange(T), new_cur_t)
@@ -308,7 +317,7 @@ def _execute_refill(store: TripleStore, relax: RelaxTable,
 
     def lane_step(strm, s: _LoopState) -> _LoopState:
         live = ~s.done
-        new = _step(strm, s, cfg, N)
+        new = _step(strm, s, cfg)
         # Freeze discipline: only result-bearing fields of an idle lane
         # are pinned; its merge state may mutate harmlessly (nothing
         # reads it — a refill replaces it wholesale).
